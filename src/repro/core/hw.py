@@ -1,8 +1,9 @@
 """Target hardware model: TPU v5e pod (the simulation/roofline substrate).
 
-All DistSim analytical event times and every roofline term in
-EXPERIMENTS.md derive from these constants. The container has no TPU —
-these describe the TARGET, per the assignment:
+All DistSim analytical event times and every roofline term derive from
+these constants. They are published peaks of the target chip, not
+measurements (Google Cloud documentation, "TPU v5e"); times measured on
+the chip come from ``MeasuredProvider`` and ``chip_smoke.py``:
 
     197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
 """

@@ -35,9 +35,9 @@ that are ≥ 0. Batch times are therefore bit-identical per candidate to
 segment sums whose FP summation order differs from the sequential
 loop — they match to rounding, not to the bit, and are not gated.
 
-Backends: ``numpy`` (default — the bit-identical reference),
-``jax`` (``lax.scan`` over steps) and ``pallas`` (fused per-step
-max/accumulate kernel) for accelerators; see
+Backends: ``numpy`` (default — the bit-identical reference) and
+``jax`` (``lax.scan`` over steps, float32, held to the numpy ranking by
+:func:`same_ranking`) for accelerators; see
 :mod:`repro.kernels.megabatch_scan`. ``auto`` picks numpy unless jax
 reports a GPU/TPU. jax is imported lazily — environments without it
 (the numpy-only CI jobs) never touch the accelerator backends.
@@ -54,7 +54,14 @@ from repro.core.engine import EventFlowEngine
 #: global slot 0 — constant end time 0.0, the identity dependency.
 DUMMY_SLOT = 0
 
-BACKENDS = ("auto", "numpy", "jax", "pallas")
+BACKENDS = ("auto", "numpy", "jax")
+
+#: relative gap below which two candidates' numpy batch times count as
+#: a tie when the float32 device path is held to the numpy ranking. Ties
+#: arise where two candidates run the same schedule in another order
+#: (e.g. gpipe and 1f1b at pp=1): float64 splits them in the last bits,
+#: float32 rounds them either way.
+RANK_RTOL = 1e-5
 
 
 @dataclasses.dataclass
@@ -336,8 +343,7 @@ class MegaBatch:
         from repro.kernels import megabatch_scan
         dep, delay = self._stacked()
         ends, starts = megabatch_scan.scan_steps(
-            self._out, dep, delay, self._dur, self.n_slots,
-            backend=backend)
+            self._out, dep, delay, self._dur, self.n_slots)
         return ends, starts, backend
 
     def predict_times(self, backend: str = "auto") -> np.ndarray:
@@ -380,6 +386,22 @@ class MegaBatch:
         bubble = 1.0 - mean_util
         return MegaPredict(batch_times, bubble, used, K, self.T,
                            self.n_slots)
+
+
+def same_ranking(reference: np.ndarray, times: np.ndarray) -> bool:
+    """True when ``times`` ranks the candidates as ``reference`` (the
+    numpy batch times) does, except within groups of candidates whose
+    reference times lie within :data:`RANK_RTOL` of their neighbour,
+    which are tied and may come in any order."""
+    reference = np.asarray(reference)
+    order = np.argsort(reference, kind="stable")
+    ref = reference[order]
+    new_group = np.ones(len(ref), dtype=bool)
+    new_group[1:] = ref[1:] - ref[:-1] > RANK_RTOL * np.abs(ref[1:])
+    group = np.empty(len(ref), dtype=np.int64)
+    group[order] = np.cumsum(new_group)
+    ranked = group[np.argsort(np.asarray(times), kind="stable")]
+    return bool(np.all(np.diff(ranked) >= 0))
 
 
 def megabatch_predict(engines: Sequence[EventFlowEngine],

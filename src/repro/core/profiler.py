@@ -7,11 +7,12 @@ Each unique event is profiled ONCE:
   profiling hardware). Used for full-size configs and the target cluster.
 
 * ``MeasuredProvider`` — actually executes each compute event's GEMMs with
-  jit'd JAX on this host and times them (the analogue of the paper's
-  2-node profiling; our container is 1 CPU host). Communication events
-  still use the ring model — with 1 host there is no link to measure, the
-  same situation the paper solves by extrapolating ≤8-way profiles
-  (§4.2: error contribution <2%).
+  jit'd JAX on the default device and times them (the analogue of the
+  paper's 2-node profiling; here one TPU v5e chip, and the CPU backend
+  in the tests, where the times mean nothing). Communication events
+  still use the ring model — one chip has no link to measure, the same
+  situation the paper solves by extrapolating ≤8-way profiles (§4.2:
+  error contribution <2%).
 
 Times are cached per event — repeated strategies re-use profiles, as the
 paper notes ("events' time can be stored and reused").
@@ -169,19 +170,28 @@ class AnalyticalProvider(Provider):
 
 
 class MeasuredProvider(Provider):
-    """Times real jit'd op groups on this host (reduced configs only).
+    """Times real jit'd op groups on the default JAX device.
 
     An event's GEMMs are executed inside ONE jitted function — the
     operator-level granularity the paper profiles (per-op dispatch
     overheads amortize exactly as in a real fused program). A per-GEMM
     elementwise epilogue approximates the activation/softmax traffic
-    between the GEMMs.
+    between the GEMMs. Inputs are float32, so the profile matches a
+    float32 step. :attr:`compile_seconds` and :attr:`timing_seconds`
+    split the profiling cost between compiling and running.
     """
 
     def __init__(self, cluster: ClusterSpec = V5E_POD, reps: int = 3):
         super().__init__(cluster)
         self.reps = reps
         self._group_cache: Dict[tuple, float] = {}
+        self.compile_seconds = 0.0
+        self.timing_seconds = 0.0
+
+    @property
+    def n_groups(self) -> int:
+        """Distinct GEMM groups compiled and timed."""
+        return len(self._group_cache)
 
     def _clear_derived(self) -> None:
         # without this, a clear_cache() followed by re-profiling would
@@ -191,6 +201,7 @@ class MeasuredProvider(Provider):
     def bare(self) -> "MeasuredProvider":
         p = super().bare()
         p._group_cache = {}
+        p.compile_seconds = p.timing_seconds = 0.0
         return p
 
     def _time_group(self, dims: tuple) -> float:
@@ -210,13 +221,17 @@ class MeasuredProvider(Provider):
                 acc = acc + y.sum()
             return acc
 
-        f = jax.jit(run)
-        f(inputs).block_until_ready()         # compile
+        start = time.perf_counter()
+        f = jax.jit(run).lower(inputs).compile()
+        compiled = time.perf_counter()
+        self.compile_seconds += compiled - start
+        f(inputs).block_until_ready()         # warm-up
         best = float("inf")
         for _ in range(self.reps):
             t0 = time.perf_counter()
             f(inputs).block_until_ready()
             best = min(best, time.perf_counter() - t0)
+        self.timing_seconds += time.perf_counter() - compiled
         self._group_cache[dims] = best
         return best
 

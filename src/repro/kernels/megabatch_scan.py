@@ -1,4 +1,4 @@
-"""Accelerator backends for the mega-batch predict recurrence.
+"""Device backend for the mega-batch predict recurrence.
 
 :class:`repro.core.megabatch.MegaBatch` compiles K candidate engines
 into ``(T, K)`` step arrays; this module evaluates the step recurrence
@@ -6,17 +6,15 @@ into ``(T, K)`` step arrays; this module evaluates the step recurrence
     start[j] = max over 3 deps of (ends[dep[j]] + delay[j])
     ends[out[j]] = start[j] + dur[j]
 
-on jax: a ``lax.scan`` over the T steps (the dependency chain is
-inherently sequential; each step is a (K, 3) gather + add + row-max),
-and optionally a pallas kernel that keeps the global ``ends`` vector
-resident in VMEM across the sequential grid — the per-step
-max/accumulate hot loop fused into one kernel launch.
+on jax as a ``lax.scan`` over the T steps (the dependency chain is
+inherently sequential; each step is a (K, 3) gather + add + row-max).
 
-These paths run in whatever precision jax is configured for (float32
+This path runs in whatever precision jax is configured for (float32
 by default); the numpy path in :mod:`repro.core.megabatch` remains the
-bit-identical reference and the default on CPU. Import of jax is
-deferred to call time so numpy-only environments can import this
-module's callers freely.
+bit-identical reference and the default on CPU. The device path is held
+to the numpy path's ranking, not its bits. Import of jax is deferred to
+call time so numpy-only environments can import this module's callers
+freely.
 """
 from __future__ import annotations
 
@@ -41,28 +39,23 @@ def accelerator_backend() -> Optional[str]:
     return b if b in ("gpu", "tpu") else None
 
 
-def _require_jax() -> None:
-    if not HAS_JAX:
-        raise RuntimeError(
-            "megabatch backend 'jax'/'pallas' requires jax; this "
-            "environment has numpy only — use backend='numpy'")
-
-
 def scan_steps(out: np.ndarray, dep: np.ndarray, delay: np.ndarray,
-               dur: np.ndarray, n_slots: int, backend: str = "jax",
-               interpret: Optional[bool] = None
+               dur: np.ndarray, n_slots: int
                ) -> Tuple[np.ndarray, np.ndarray]:
     """Evaluate the step recurrence; returns float64 numpy
     ``(ends, starts)`` vectors of length ``n_slots`` (upcast from the
     jax dtype in use)."""
-    _require_jax()
-    if backend == "jax":
-        ends, step_starts = _scan_jax(out, dep, delay, dur, n_slots)
-    elif backend == "pallas":
-        ends, step_starts = _scan_pallas(out, dep, delay, dur, n_slots,
-                                         interpret=interpret)
-    else:
-        raise ValueError(f"unknown scan backend {backend!r}")
+    if not HAS_JAX:
+        raise RuntimeError(
+            "megabatch backend 'jax' requires jax; this environment has "
+            "numpy only — use backend='numpy'")
+    import jax.numpy as jnp
+
+    dtype = jnp.result_type(float)      # honors jax_enable_x64
+    ends0 = jnp.zeros((n_slots,), dtype=dtype)
+    xs = (jnp.asarray(out), jnp.asarray(dep),
+          jnp.asarray(delay, dtype=dtype), jnp.asarray(dur, dtype=dtype))
+    ends, step_starts = _scan_jit(ends0, xs)
     ends = np.asarray(ends, dtype=np.float64)
     # scatter per-step start rows back to slot order (trash-slot rows
     # overwrite each other; their value is never read)
@@ -71,75 +64,16 @@ def scan_steps(out: np.ndarray, dep: np.ndarray, delay: np.ndarray,
     return ends, starts
 
 
-def _scan_jax(out, dep, delay, dur, n_slots):
-    import jax.numpy as jnp
-    from jax import lax
-
-    dtype = jnp.result_type(float)      # honors jax_enable_x64
-
-    def step(ends, xs):
-        o, dp_, dl, du = xs
-        start = jnp.max(ends[dp_] + dl, axis=-1)
-        return ends.at[o].set(start + du), start
-
-    ends0 = jnp.zeros((n_slots,), dtype=dtype)
-    xs = (jnp.asarray(out), jnp.asarray(dep),
-          jnp.asarray(delay, dtype=dtype), jnp.asarray(dur, dtype=dtype))
-    return jax.jit(lambda e, x: lax.scan(step, e, x))(ends0, xs)
+def _step(ends, xs):
+    o, dp_, dl, du = xs
+    start = (ends[dp_] + dl).max(axis=-1)
+    return ends.at[o].set(start + du), start
 
 
-def _scan_pallas(out, dep, delay, dur, n_slots, interpret=None):
-    """Per-step max/accumulate as a pallas kernel.
+def scan_program(ends0, xs):
+    """The device program: ``(ends, per-step starts)`` after T steps.
+    Module-level so that jit caches one executable per shape."""
+    return jax.lax.scan(_step, ends0, xs)
 
-    The grid iterates the T steps sequentially; ``ends``/``starts``
-    use a constant index map so the same VMEM block is revisited every
-    step — the scan state never round-trips to HBM between steps.
-    ``interpret`` defaults to True off-TPU/GPU so the kernel is
-    exercisable (and tested) on CPU.
-    """
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = accelerator_backend() is None
-    T, K = out.shape
-    dtype = jnp.result_type(float)
-
-    def kernel(out_ref, dep_ref, delay_ref, dur_ref, ends_ref,
-               starts_ref):
-        j = pl.program_id(0)
-
-        @pl.when(j == 0)
-        def _init():
-            ends_ref[...] = jnp.zeros_like(ends_ref)
-            starts_ref[...] = jnp.zeros_like(starts_ref)
-
-        ends = ends_ref[...]
-        start = jnp.max(ends[dep_ref[0]] + delay_ref[0], axis=-1)
-        o = out_ref[0]
-        ends_ref[...] = ends.at[o].set(start + dur_ref[0])
-        starts_ref[...] = starts_ref[...].at[o].set(start)
-
-    row = lambda j: (j, 0)                          # noqa: E731
-    row3 = lambda j: (j, 0, 0)                      # noqa: E731
-    full = lambda j: (0,)                           # noqa: E731
-    ends, starts = pl.pallas_call(
-        kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, K), row),
-            pl.BlockSpec((1, K, 3), row3),
-            pl.BlockSpec((1, K, 3), row3),
-            pl.BlockSpec((1, K), row),
-        ],
-        out_specs=[pl.BlockSpec((n_slots,), full),
-                   pl.BlockSpec((n_slots,), full)],
-        out_shape=[jax.ShapeDtypeStruct((n_slots,), dtype),
-                   jax.ShapeDtypeStruct((n_slots,), dtype)],
-        interpret=interpret,
-    )(jnp.asarray(out), jnp.asarray(dep),
-      jnp.asarray(delay, dtype=dtype), jnp.asarray(dur, dtype=dtype))
-    # pallas wrote per-slot starts directly; return them in the same
-    # (ends, per-step starts) convention scan_steps normalizes — remap
-    # by gathering the slot starts at each step's out row.
-    return ends, jnp.asarray(starts)[jnp.asarray(out)]
+_scan_jit = jax.jit(scan_program) if HAS_JAX else None

@@ -2,8 +2,10 @@
 
 ``flash_attention`` matches ``repro.models.layers.attention``'s calling
 convention ((B,S,H,hd) GQA layout + position arrays) so the model can
-select ``attn_impl="pallas"``. On this CPU container the kernels run in
-interpret mode (the TPU lowering path is identical code).
+select ``attn_impl="pallas"``. On a TPU the kernels are compiled by
+Mosaic; on the CPU backend, where the tests run, they run in the Pallas
+interpreter (same kernel code, no lowering). There is no fallback to
+interpret mode or to the reference on any other backend.
 """
 from __future__ import annotations
 
@@ -15,7 +17,10 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as fa
 from repro.kernels import rmsnorm as rn
 
-INTERPRET = True    # CPU container; False on real TPU
+
+def _interpret() -> bool:
+    """Interpret only on the CPU backend; read at trace time."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit,
@@ -36,11 +41,11 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal=True,
     vb = v.transpose(0, 2, 1, 3).reshape(b * h, -1, hd)
     ob = fa.flash_attention_bh(qb, kb, vb, causal=causal, window=window,
                                block_q=block_q, block_kv=block_kv,
-                               interpret=INTERPRET)
+                               interpret=_interpret())
     return ob.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
 def rmsnorm(x, scale, eps=1e-6, block_rows=128):
     return rn.rmsnorm(x, scale, eps=eps, block_rows=block_rows,
-                      interpret=INTERPRET)
+                      interpret=_interpret())

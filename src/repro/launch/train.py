@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2_1_5b \
         --smoke --steps 50 [--ckpt-dir /tmp/ckpt]
 
-``--smoke`` trains the reduced same-family config on this host (the full
-configs are for the pod dry-run / real TPU deployment, where this same
-driver runs under `jax.distributed.initialize()` with the production
-mesh — see repro/launch/dryrun.py for the sharding entry points).
+``--smoke`` trains the reduced same-family config (the full configs are
+for the pod dry-run / real TPU deployment, where this same launcher runs
+under `jax.distributed.initialize()` with the production mesh — see
+repro/launch/dryrun.py for the sharding entry points). Compiled programs
+go to the persistent cache that ``repro.launch.cache`` sets up.
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import argparse
 import jax.numpy as jnp
 
 from repro.configs.base import get_config, list_archs, smoke_config
+from repro.launch.cache import setup_compile_cache
 from repro.models.layers import ModelOptions
 from repro.train.optimizer import AdamWConfig
 from repro.train.step import TrainConfig
@@ -35,6 +37,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--save-every", type=int, default=50)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -159,7 +159,7 @@ def _match_vma(tree, ref):
     vma = tuple(getattr(jax.typeof(ref), "vma", ()))
     if not vma:
         return tree
-    return jax.tree.map(lambda x: jax.lax.pvary(x, vma), tree)
+    return jax.tree.map(lambda x: jax.lax.pcast(x, vma, to="varying"), tree)
 
 
 def _flash_fwd_impl(q, k, v, q_pos, k_pos, causal, window,
@@ -418,7 +418,7 @@ def ring_attention(q, k, v, q_pos, k_pos, axis_name: str, causal=True,
     def _vary(x):
         if axis_name in getattr(jax.typeof(x), "vma", ()):
             return x
-        return jax.lax.pvary(x, (axis_name,))
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     k, v, k_pos = _vary(k), _vary(v), _vary(k_pos)
 

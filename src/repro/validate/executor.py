@@ -31,7 +31,7 @@ import sys
 from concurrent.futures import ProcessPoolExecutor
 from typing import List, Optional, Sequence, Tuple
 
-from repro.core.profiler import Provider
+from repro.core.profiler import MeasuredProvider, Provider
 from repro.validate.build_cache import BuildCache, BuildCacheStats
 from repro.validate.sweep import (CellResult, Thresholds, ValidationCell,
                                   run_cell)
@@ -125,7 +125,16 @@ def run_parallel(cells: Sequence[ValidationCell], provider: Provider,
     builds, flushing its own additions back. Results and accounting
     stay identical; the store — not a per-run in-memory cache — is
     what survives for the next process.
+
+    A :class:`MeasuredProvider` times events on the device, and a chip
+    belongs to one process at a time, so ``jobs > 1`` with one raises
+    ``ValueError``.
     """
+    if int(jobs) > 1 and isinstance(provider, MeasuredProvider):
+        raise ValueError(
+            "jobs > 1 needs a provider that does not measure on the "
+            "device: each worker process would profile on a chip that "
+            "another process holds. Run a MeasuredProvider with jobs=1.")
     thresholds = thresholds or Thresholds()
     cells = list(cells)
     jobs = max(1, min(int(jobs), len(cells) or 1))

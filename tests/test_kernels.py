@@ -8,15 +8,6 @@ from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(42)
 
-# Version gate, not a blanket xfail: these tests use jax>=0.6 APIs
-# (jax.typeof, jax.lax.axis_size) and auto-activate — instead of
-# silently xpassing — once the pinned jax is upgraded.
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:2])
-needs_jax_0_6 = pytest.mark.skipif(
-    _JAX_VERSION < (0, 6),
-    reason=f"requires jax>=0.6 APIs (jax.typeof / jax.lax.axis_size); "
-           f"running jax {jax.__version__} — runs again after upgrade")
-
 
 def _qkv(b, s, h, kh, hd, dtype):
     ks = jax.random.split(KEY, 3)
@@ -103,7 +94,6 @@ def test_model_layer_pallas_path_matches_naive():
                                atol=2e-5, rtol=2e-5)
 
 
-@needs_jax_0_6
 def test_combine_attention_partials_matches_full():
     """Online-softmax identity: attention over the full KV equals the
     exp-weighted combination of partials over disjoint KV shards — the
@@ -124,18 +114,15 @@ def test_combine_attention_partials_matches_full():
                                atol=2e-5, rtol=2e-5)
 
 
-@needs_jax_0_6
 def test_ring_attention_single_ring():
     """ring_attention on a 1-element ring == plain flash attention."""
-    import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models import layers as L
     q, k, v = _qkv(1, 64, 4, 2, 32, jnp.float32)
     qpos = jnp.broadcast_to(jnp.arange(64), (1, 64))
     mesh = jax.make_mesh((1,), ("cp",))
     # realistic usage: sequence sharded over the ring axis
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v, qp: L.ring_attention(q, k, v, qp, qp, "cp",
                                              block_q=32, block_kv=32),
         mesh=mesh,

@@ -1,7 +1,6 @@
 """Roofline HLO analyzer: trip counts, collective traffic, flops."""
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.core.roofline import collective_bytes, hlo_stats
 
@@ -60,9 +59,6 @@ ENTRY %main (a: f32[64]) -> f32[64] {
     assert out["count"] == 1
 
 
-@pytest.mark.xfail(
-    reason="seed-known: uses jax.set_mesh, absent in jax<=0.4.x",
-    strict=False)
 def test_while_body_collectives_multiplied():
     """Collectives inside a lax.scan body scale with trip count."""
     mesh = jax.make_mesh((1,), ("d",))

@@ -184,6 +184,20 @@ def test_parallel_accumulates_shard_cache_stats():
     assert cache.stats.engine_misses >= len(MATRIX) // 2
 
 
+def test_parallel_refuses_provider_that_measures_on_device():
+    """A chip belongs to one process: worker processes must not each
+    profile on it, so jobs > 1 with a MeasuredProvider raises before
+    any worker starts (and before any profiling)."""
+    from repro.core import MeasuredProvider
+    from repro.validate.executor import run_parallel
+    provider = MeasuredProvider(A40_CLUSTER)
+    with pytest.raises(ValueError, match="device"):
+        run_sweep(MATRIX[:2], provider=provider, seeds=(0,), jobs=2)
+    with pytest.raises(ValueError, match="device"):
+        run_parallel(MATRIX[:2], provider, seeds=(0,), jobs=2)
+    assert provider.stats.evaluations == 0
+
+
 # --------------------------------------------------------------------------
 # satellite: DistSim.engine(positions) structural identity
 # --------------------------------------------------------------------------

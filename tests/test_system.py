@@ -2,9 +2,9 @@
 
 1. DistSim models a strategy space and its ranking is consistent with
    the replay oracle (the paper's core claim, §6/Table 2).
-2. The real training loop trains a reduced model and the MEASURED step
-   time feeds a DistSim 1M1P1D prediction that matches the measured
-   step time (model-vs-reality check, the paper's Fig. 3 motivation).
+2. A DistSim 1M1P1D prediction from MeasuredProvider profiles exactly
+   the graph's unique compute events (the comparison with a measured
+   step runs on the chip, in ``chip_smoke.py``).
 3. Checkpoint/restart mid-run reproduces the uninterrupted loss curve.
 """
 import tempfile
@@ -35,27 +35,30 @@ def test_search_ranking_consistent_with_replay():
 
 
 def test_measured_provider_predicts_real_step_time():
-    """1M1P1D with MeasuredProvider ≈ real jit step time on this host —
-    the no-simulation sanity anchor. Uses a GEMM-dominated reduced
-    config (at toy widths, non-GEMM overheads dominate the real step and
-    no operator-level profile can see them)."""
+    """1M1P1D with MeasuredProvider: what a CPU run can show. The
+    prediction is finite and positive, and the provider timed exactly
+    the graph's unique compute events, each distinct GEMM group once.
+    The comparison with a measured step needs the chip and lives in
+    ``chip_smoke.py``."""
     import dataclasses
+    from repro.core.events import stage_event_set
     cfg = dataclasses.replace(
         smoke_config(get_config("gpt2_345m")), d_model=512, d_ff=2048,
         n_layers=4, vocab=2048, n_heads=8, n_kv_heads=8)
-    r = fit(cfg, loop=LoopConfig(steps=6, seq_len=256, global_batch=4,
-                                 log_every=100), verbose=False)
-    measured = float(np.median(r.step_times[2:]))
-
-    provider = MeasuredProvider()
+    provider = MeasuredProvider(reps=1)
     sim = DistSim(cfg, Strategy(), global_batch=4, seq=256,
                   provider=provider)
     predicted = sim.simulate().batch_time
-    # CPU timing is noisy and the event model is layer-granular; require
-    # factor-3 agreement (paper gets <4% with same-hardware profiling)
-    assert predicted > 0
-    assert 1 / 3 < predicted / measured < 3.0, \
-        f"predicted {predicted:.4f}s vs measured {measured:.4f}s"
+    assert np.isfinite(predicted) and predicted > 0
+
+    graph = stage_event_set(sim.positions())
+    compute = {e for e in graph if e.kind == "compute"}
+    profiled = provider.cache_snapshot()
+    assert {e for e in profiled if e.kind == "compute"} == compute
+    assert provider.stats.evaluations == len(profiled)
+    groups = {tuple((g.m, g.n, g.k) for g in e.gemms) for e in compute}
+    assert provider.n_groups == len(groups - {()})
+    assert all(profiled[e] > 0 for e in compute if e.gemms)
 
 
 def test_checkpoint_restart_reproduces_run():

@@ -330,10 +330,9 @@ def test_error_feedback_unbiased_over_time():
 
 def test_compressed_psum_single_axis():
     mesh = jax.make_mesh((1,), ("data",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     g = {"w": jnp.linspace(-1, 1, 32)}
-    f = shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
+    f = jax.shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
                   in_specs=(P(),), out_specs=P())
     out = f(g)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
