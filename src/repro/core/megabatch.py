@@ -50,6 +50,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.engine import EventFlowEngine
+from repro.obs import span
 
 #: global slot 0 — constant end time 0.0, the identity dependency.
 DUMMY_SLOT = 0
@@ -93,7 +94,11 @@ class MegaBatch:
 
     def __init__(self, engines: Sequence[EventFlowEngine], perturb=None,
                  verify=None):
-        engines = list(engines)
+        with span("distsim.megabatch.compile"):
+            self._compile(list(engines), perturb, verify)
+
+    def _compile(self, engines: List[EventFlowEngine], perturb,
+                 verify) -> None:
         self.engines = engines
         # a Perturbation's straggler multipliers scale the profiled
         # means at compile time (same operand pairings as the engine's
@@ -330,9 +335,10 @@ class MegaBatch:
 
     def _stacked(self) -> Tuple[np.ndarray, np.ndarray]:
         """(T, K, 3) dep/delay stacks — the accelerator-backend layout."""
-        dep = np.stack([self._dep0, self._dep1, self._dep2], axis=-1)
-        delay = np.stack([np.zeros_like(self._del1), self._del1,
-                          self._del2], axis=-1)
+        with span("distsim.scan.stack"):
+            dep = np.stack([self._dep0, self._dep1, self._dep2], axis=-1)
+            delay = np.stack([np.zeros_like(self._del1), self._del1,
+                              self._del2], axis=-1)
         return dep, delay
 
     def _eval(self, backend: str) -> Tuple[np.ndarray, np.ndarray, str]:
@@ -352,10 +358,17 @@ class MegaBatch:
         return self.predict(backend).batch_times
 
     def predict(self, backend: str = "auto") -> MegaPredict:
-        if self.K == 0:
-            return MegaPredict(np.zeros(0), np.zeros(0), "numpy", 0,
-                               self.T, self.n_slots)
-        ends, starts, used = self._eval(backend)
+        with span("distsim.megabatch.predict"):
+            if self.K == 0:
+                return MegaPredict(np.zeros(0), np.zeros(0), "numpy", 0,
+                                   self.T, self.n_slots)
+            ends, starts, used = self._eval(backend)
+            with span("distsim.megabatch.epilogue"):
+                return self._epilogue(ends, starts, used)
+
+    def _epilogue(self, ends: np.ndarray, starts: np.ndarray,
+                  used: str) -> MegaPredict:
+        """Batch times and bubbles from the recurrence's slot times."""
         K, ppmax, total = self.K, self.ppmax, self.total
         task_end = ends[1: total + 1]
         task_start = starts[1: total + 1]

@@ -27,6 +27,7 @@ from repro.core.costmodel import (ClusterSpec, V5E_POD, collective_time,
                                   compute_time, hbm_time, p2p_time,
                                   ring_hops, ring_volume_factor)
 from repro.core.events import Event
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -210,10 +211,12 @@ class MeasuredProvider(Provider):
         import jax
         import jax.numpy as jnp
 
-        inputs = [(jnp.ones((m, k), jnp.float32),
-                   jnp.ones((k, n), jnp.float32)) for m, n, k in dims]
+        with span("distsim.profile.inputs"):
+            inputs = [(jnp.ones((m, k), jnp.float32),
+                       jnp.ones((k, n), jnp.float32)) for m, n, k in dims]
 
-        def run(args):
+        # the name reaches the device: its modules read jit_profile_group
+        def profile_group(args):
             acc = jnp.zeros((), jnp.float32)
             for a, b in args:
                 y = a @ b
@@ -222,15 +225,22 @@ class MeasuredProvider(Provider):
             return acc
 
         start = time.perf_counter()
-        f = jax.jit(run).lower(inputs).compile()
+        with span("distsim.profile.lower"):
+            lowered = jax.jit(profile_group).lower(inputs)
+        with span("distsim.profile.compile"):
+            f = lowered.compile()
         compiled = time.perf_counter()
         self.compile_seconds += compiled - start
-        f(inputs).block_until_ready()         # warm-up
-        best = float("inf")
-        for _ in range(self.reps):
-            t0 = time.perf_counter()
+        with span("distsim.profile.warmup"):
             f(inputs).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
+        best = float("inf")
+        # no span inside a timed repetition: the spans leave the
+        # profiled times as they are
+        with span("distsim.profile.reps"):
+            for _ in range(self.reps):
+                t0 = time.perf_counter()
+                f(inputs).block_until_ready()
+                best = min(best, time.perf_counter() - t0)
         self.timing_seconds += time.perf_counter() - compiled
         self._group_cache[dims] = best
         return best

@@ -31,6 +31,7 @@ from repro.core.profiler import (AnalyticalProvider, Provider,
                                  profile_events, profiling_cost)
 from repro.core.scenario import TRAIN, Scenario
 from repro.core.timeline import Timeline, TimelineBatch
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -212,17 +213,23 @@ class DistSim:
             return simulate_degraded(
                 self, perturb, seeds=seeds, jitter_sigma=jitter_sigma,
                 straggler_sigma=straggler_sigma, clock_sigma=clock_sigma)
-        sc = self.scenario if scenario is None else scenario
-        engine = self.engine(positions, scenario=sc)
-        if seeds is None:
-            return SimBatch(engine.run_batched(None), self.global_batch,
-                            self.seq, "predict", sc)
-        if isinstance(seeds, (int, np.integer)):
-            seeds = [int(seeds)]
-        batch = engine.run_batched(
-            list(seeds), jitter_sigma=jitter_sigma,
-            straggler_sigma=straggler_sigma, clock_sigma=clock_sigma)
-        return SimBatch(batch, self.global_batch, self.seq, "replay", sc)
+        with span("distsim.simulate"):
+            sc = self.scenario if scenario is None else scenario
+            engine = self.engine(positions, scenario=sc)
+            if seeds is None:
+                with span("distsim.run"):
+                    batch = engine.run_batched(None)
+                return SimBatch(batch, self.global_batch, self.seq,
+                                "predict", sc)
+            if isinstance(seeds, (int, np.integer)):
+                seeds = [int(seeds)]
+            with span("distsim.run"):
+                batch = engine.run_batched(
+                    list(seeds), jitter_sigma=jitter_sigma,
+                    straggler_sigma=straggler_sigma,
+                    clock_sigma=clock_sigma)
+            return SimBatch(batch, self.global_batch, self.seq, "replay",
+                            sc)
 
     # ---- deprecated 5-method surface (thin delegating wrappers) ----
     def predict(self, positions: Optional[List[Stage]] = None) -> SimResult:
@@ -316,9 +323,10 @@ class DistSim:
         events — precompute once, pass to simulate() and the search
         pruner so candidates don't rebuild the model graph."""
         sc = self.scenario if scenario is None else scenario
-        return build_positions(self.cfg, self.strategy,
-                               self.microbatch(sc), self.seq,
-                               self.provider.cluster, scenario=sc)
+        with span("distsim.positions"):
+            return build_positions(self.cfg, self.strategy,
+                                   self.microbatch(sc), self.seq,
+                                   self.provider.cluster, scenario=sc)
 
     def engine(self, positions: Optional[List[Stage]] = None,
                scenario: Optional[Scenario] = None) -> EventFlowEngine:
@@ -338,16 +346,18 @@ class DistSim:
         if positions is None:
             cached = self._engines.get(sc)
             if cached is None or self._stale(cached):
-                cached = EventFlowEngine(
-                    self.positions(sc), self.strategy, self.provider,
-                    scenario=sc)
+                stages = self.positions(sc)
+                with span("distsim.engine"):
+                    cached = EventFlowEngine(stages, self.strategy,
+                                             self.provider, scenario=sc)
                 self._engines[sc] = cached
             return cached
         key = (sc, stage_signature(positions))
         if (self._engine is None or self._engine_key != key
                 or self._stale(self._engine)):
-            self._engine = EventFlowEngine(positions, self.strategy,
-                                           self.provider, scenario=sc)
+            with span("distsim.engine"):
+                self._engine = EventFlowEngine(positions, self.strategy,
+                                               self.provider, scenario=sc)
             self._engine_key = key
         return self._engine
 

@@ -22,6 +22,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.obs import span
+
 try:                              # deferred everywhere else; this flag
     import jax                    # only gates backend availability
     HAS_JAX = True
@@ -52,15 +54,20 @@ def scan_steps(out: np.ndarray, dep: np.ndarray, delay: np.ndarray,
     import jax.numpy as jnp
 
     dtype = jnp.result_type(float)      # honors jax_enable_x64
-    ends0 = jnp.zeros((n_slots,), dtype=dtype)
-    xs = (jnp.asarray(out), jnp.asarray(dep),
-          jnp.asarray(delay, dtype=dtype), jnp.asarray(dur, dtype=dtype))
-    ends, step_starts = _scan_jit(ends0, xs)
-    ends = np.asarray(ends, dtype=np.float64)
-    # scatter per-step start rows back to slot order (trash-slot rows
-    # overwrite each other; their value is never read)
-    starts = np.zeros(n_slots)
-    starts[np.asarray(out)] = np.asarray(step_starts, dtype=np.float64)
+    with span("distsim.scan.put"):
+        ends0 = jnp.zeros((n_slots,), dtype=dtype)
+        xs = (jnp.asarray(out), jnp.asarray(dep),
+              jnp.asarray(delay, dtype=dtype),
+              jnp.asarray(dur, dtype=dtype))
+    with span("distsim.scan.run"):
+        ends, step_starts = _scan_jit(ends0, xs)
+    with span("distsim.scan.fetch"):
+        ends = np.asarray(ends, dtype=np.float64)
+        # scatter per-step start rows back to slot order (trash-slot
+        # rows overwrite each other; their value is never read)
+        starts = np.zeros(n_slots)
+        starts[np.asarray(out)] = np.asarray(step_starts,
+                                             dtype=np.float64)
     return ends, starts
 
 

@@ -37,6 +37,7 @@ from repro.core.costmodel import ClusterSpec, V5E_POD
 from repro.core.events import Strategy, stage_event_set
 from repro.core.profiler import AnalyticalProvider, Provider
 from repro.core.simulator import DistSim
+from repro.obs import span
 from repro.search.cache import ProfileCache
 from repro.search.prune import (HBM_BUDGET, estimate_memory,
                                 work_lower_bound)
@@ -162,32 +163,37 @@ class SearchEngine:
                microbatches: Optional[Sequence[int]] = None,
                schedules: Sequence[str] = ("1f1b",),
                zero1_options: Sequence[bool] = (False,)) -> SearchResult:
-        t0 = time.perf_counter()
-        stats = SearchStats()
-        base_evals = self.cache.evaluations if self.share_cache else 0
-        base_hits = self.cache.hits if self.share_cache else 0
-        grid = enumerate_candidates(n_devices, global_batch, microbatches,
-                                    schedules, zero1_options)
-        by_cluster: Dict[str, List[SearchEntry]] = {}
-        search_cluster = (self._search_cluster_megabatch if self.megabatch
-                          else self._search_cluster)
-        for cluster in self.clusters:
-            by_cluster[cluster.name] = search_cluster(
-                cluster, grid, global_batch, seq, stats)
+        with span("distsim.search"):
+            t0 = time.perf_counter()
+            stats = SearchStats()
+            base_evals = self.cache.evaluations if self.share_cache else 0
+            base_hits = self.cache.hits if self.share_cache else 0
+            grid = enumerate_candidates(n_devices, global_batch,
+                                        microbatches, schedules,
+                                        zero1_options)
+            by_cluster: Dict[str, List[SearchEntry]] = {}
+            search_cluster = (self._search_cluster_megabatch
+                              if self.megabatch else self._search_cluster)
+            for cluster in self.clusters:
+                by_cluster[cluster.name] = search_cluster(
+                    cluster, grid, global_batch, seq, stats)
 
-        entries = sorted((e for es in by_cluster.values() for e in es),
-                         key=lambda e: e.batch_time)
-        for es in by_cluster.values():
-            es.sort(key=lambda e: e.batch_time)
-        if self.share_cache:
-            stats.provider_evaluations = self.cache.evaluations - base_evals
-            stats.cache_hits = self.cache.hits - base_hits
-        stats.wall_time_s = time.perf_counter() - t0
-        pareto = pareto_frontier(
-            [e for e in entries if e.feasible and not e.pruned])
-        return SearchResult(entries, by_cluster, pareto, stats,
-                            cluster_specs={c.name: c
-                                           for c in self.clusters})
+            with span("distsim.search.rank"):
+                entries = sorted(
+                    (e for es in by_cluster.values() for e in es),
+                    key=lambda e: e.batch_time)
+                for es in by_cluster.values():
+                    es.sort(key=lambda e: e.batch_time)
+                if self.share_cache:
+                    stats.provider_evaluations = (self.cache.evaluations
+                                                  - base_evals)
+                    stats.cache_hits = self.cache.hits - base_hits
+                stats.wall_time_s = time.perf_counter() - t0
+                pareto = pareto_frontier(
+                    [e for e in entries if e.feasible and not e.pruned])
+            return SearchResult(entries, by_cluster, pareto, stats,
+                                cluster_specs={c.name: c
+                                               for c in self.clusters})
 
     def _search_cluster(self, cluster: ClusterSpec, grid: List[Candidate],
                         global_batch: int, seq: int,
@@ -268,21 +274,23 @@ class SearchEngine:
 
         rows = []        # (cand, mem, headroom, lane | None, lb | None)
         engines = []
-        for cand in grid:
-            stats.candidates += 1
-            strat = cand.strategy
-            mem = estimate_memory(self.cfg, strat, cand.microbatch, seq)
-            headroom = budget - mem
-            if self.check_memory and headroom <= 0:
-                stats.pruned_memory += 1
-                rows.append((cand, mem, headroom, None, None))
-                continue
-            eng = bcache.engine_for_cfg(self.cfg, strat, global_batch,
-                                        seq)
-            lb = (work_lower_bound(eng.build.stages, strat, provider)
-                  if self.prune else None)
-            rows.append((cand, mem, headroom, len(engines), lb))
-            engines.append(eng)
+        with span("distsim.search.engines"):
+            for cand in grid:
+                stats.candidates += 1
+                strat = cand.strategy
+                mem = estimate_memory(self.cfg, strat, cand.microbatch,
+                                      seq)
+                headroom = budget - mem
+                if self.check_memory and headroom <= 0:
+                    stats.pruned_memory += 1
+                    rows.append((cand, mem, headroom, None, None))
+                    continue
+                eng = bcache.engine_for_cfg(self.cfg, strat, global_batch,
+                                            seq)
+                lb = (work_lower_bound(eng.build.stages, strat, provider)
+                      if self.prune else None)
+                rows.append((cand, mem, headroom, len(engines), lb))
+                engines.append(eng)
 
         times = None
         bubbles = None
@@ -303,31 +311,32 @@ class SearchEngine:
 
         entries: List[SearchEntry] = []
         best_bt: Optional[float] = None
-        for cand, mem, headroom, lane, lb in rows:
-            strat = cand.strategy
-            if lane is None:
+        with span("distsim.search.replay"):
+            for cand, mem, headroom, lane, lb in rows:
+                strat = cand.strategy
+                if lane is None:
+                    entries.append(SearchEntry(
+                        strat, float("inf"), 0.0, 1.0, False, "OOM",
+                        cluster=cluster.name, mem_bytes=mem,
+                        hbm_headroom=headroom))
+                    continue
+                if self.prune and best_bt is not None and lb >= best_bt:
+                    stats.pruned_bound += 1
+                    entries.append(SearchEntry(
+                        strat, lb, 0.0, 0.0, False, "bound", pruned=True,
+                        cluster=cluster.name, mem_bytes=mem,
+                        hbm_headroom=headroom))
+                    continue
+                bt = float(times[lane])
+                stats.evaluated += 1
+                ptime = sum(provider.cached_time(e)
+                            for e in stage_event_set(
+                                engines[lane].build.stages))
                 entries.append(SearchEntry(
-                    strat, float("inf"), 0.0, 1.0, False, "OOM",
+                    strat, bt, 1.0 / bt if bt else 0.0,
+                    float(bubbles[lane]), True,
                     cluster=cluster.name, mem_bytes=mem,
-                    hbm_headroom=headroom))
-                continue
-            if self.prune and best_bt is not None and lb >= best_bt:
-                stats.pruned_bound += 1
-                entries.append(SearchEntry(
-                    strat, lb, 0.0, 0.0, False, "bound", pruned=True,
-                    cluster=cluster.name, mem_bytes=mem,
-                    hbm_headroom=headroom))
-                continue
-            bt = float(times[lane])
-            stats.evaluated += 1
-            ptime = sum(provider.cached_time(e)
-                        for e in stage_event_set(
-                            engines[lane].build.stages))
-            entries.append(SearchEntry(
-                strat, bt, 1.0 / bt if bt else 0.0,
-                float(bubbles[lane]), True,
-                cluster=cluster.name, mem_bytes=mem,
-                hbm_headroom=headroom, profile_time_s=ptime))
-            if best_bt is None or bt < best_bt:
-                best_bt = bt
+                    hbm_headroom=headroom, profile_time_s=ptime))
+                if best_bt is None or bt < best_bt:
+                    best_bt = bt
         return entries

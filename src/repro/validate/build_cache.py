@@ -38,6 +38,7 @@ from repro.core.events import Stage, Strategy
 from repro.core.hierarchy import build_positions
 from repro.core.profiler import Provider
 from repro.core.scenario import TRAIN, Scenario
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -135,8 +136,9 @@ class BuildCache:
             self.stats.positions_hits += 1
             return hit
         self.stats.positions_misses += 1
-        pos = build_positions(cfg, strat, microbatch, seq,
-                              self.provider.cluster, scenario=sc)
+        with span("distsim.build.positions"):
+            pos = build_positions(cfg, strat, microbatch, seq,
+                                  self.provider.cluster, scenario=sc)
         self._positions[key] = pos
         return pos
 
@@ -156,11 +158,12 @@ class BuildCache:
             self.stats.build_hits += 1
             return ext
         self.stats.build_misses += 1
-        pos = self.positions_for(cfg, strat, microbatch, seq, sc)
-        # with_dp_sync=None: precompute sync means whenever dp > 1 so
-        # pipedream and the syncing schedules share one build
-        build = EngineBuild(pos, strat, self.provider, with_dp_sync=None,
-                            scenario=sc)
+        with span("distsim.build.engine_build"):
+            pos = self.positions_for(cfg, strat, microbatch, seq, sc)
+            # with_dp_sync=None: precompute sync means whenever dp > 1
+            # so pipedream and the syncing schedules share one build
+            build = EngineBuild(pos, strat, self.provider,
+                                with_dp_sync=None, scenario=sc)
         self._builds[key] = build
         self._build_created(key, build)
         return build
@@ -187,9 +190,10 @@ class BuildCache:
             self.stats.engine_hits += 1
             return hit
         self.stats.engine_misses += 1
-        build = self.build_for(cfg, strat, micro, seq, scenario)
-        eng = EventFlowEngine(build.stages, strat, self.provider,
-                              build=build, scenario=scenario)
+        with span("distsim.build.engine"):
+            build = self.build_for(cfg, strat, micro, seq, scenario)
+            eng = EventFlowEngine(build.stages, strat, self.provider,
+                                  build=build, scenario=scenario)
         self._engines[key] = eng
         return eng
 
