@@ -188,6 +188,147 @@ class EngineBuild:
             self.opt_base.append(6.0 * opt_bytes * 2 / chip.hbm_bw)
 
 
+def _topo_walk(task_isf, task_pos, task_micro, n_pos: int, m: int,
+               decode: bool) -> List[Tuple[int, int]]:
+    """The ready-queue replayed with known/unknown flags instead of
+    times (see :meth:`EventFlowEngine._topo_order`). Returns the pop
+    order; a deadlocked schedule returns fewer entries than tasks."""
+    pp = len(task_isf)
+    f_known = [[False] * m for _ in range(n_pos)]
+    af_known = [[False] * m for _ in range(n_pos)]
+    ab_known = [[False] * m for _ in range(n_pos)]
+    fb_known = [False] * m
+    ptr = [0] * pp
+    n_tasks = [len(t) for t in task_isf]
+    order: List[Tuple[int, int]] = []
+    queue: deque = deque()
+    enabled = [False] * pp
+
+    def try_enable(d: int) -> None:
+        if enabled[d] or ptr[d] >= n_tasks[d]:
+            return
+        i = ptr[d]
+        pos, mic = task_pos[d][i], task_micro[d][i]
+        if task_isf[d][i]:
+            if pos == 0:
+                ok = not decode or mic == 0 or fb_known[mic - 1]
+            else:
+                ok = af_known[pos][mic]
+        else:
+            ok = f_known[pos][mic] and (pos == n_pos - 1
+                                        or ab_known[pos][mic])
+        if ok:
+            enabled[d] = True
+            queue.append(d)
+
+    for d in range(pp):
+        try_enable(d)
+    while queue:
+        d = queue.popleft()
+        enabled[d] = False
+        i = ptr[d]
+        pos, mic = task_pos[d][i], task_micro[d][i]
+        if task_isf[d][i]:
+            f_known[pos][mic] = True
+            if pos < n_pos - 1:
+                af_known[pos + 1][mic] = True
+                try_enable((pos + 1) % pp)
+            elif decode:
+                fb_known[mic] = True
+                if d != 0:
+                    try_enable(0)
+        else:
+            if pos > 0:
+                ab_known[pos - 1][mic] = True
+                try_enable((pos - 1) % pp)
+        order.append((d, i))
+        ptr[d] += 1
+        try_enable(d)
+    return order
+
+
+class TaskStructure:
+    """A pipeline schedule's task structure, free of durations.
+
+    The per-device task metadata (phase, position, microbatch, activity
+    and boundary-send names), the task count and the duration-free
+    topological order depend only on :attr:`key` — the schedule built,
+    pp, vpp, the position count, the task count ``m`` and whether the
+    scenario is decode — not on mp, dp, ZeRO-1, the model or the event
+    means. ``repro.validate.BuildCache`` builds one per key and shares
+    it among its engines; an engine built without one builds its own.
+
+    The per-device sequences are tuples, shared by every engine on the
+    structure; each engine copies only the outer per-device lists, so
+    replacing one engine's device entry leaves its siblings alone. The
+    topological order is walked on first request and kept.
+    """
+
+    def __init__(self, strat: Strategy, scenario: Scenario,
+                 n_pos: int) -> None:
+        self.key = self.key_for(strat, scenario, n_pos)
+        pp, m = strat.pp, scenario.task_count(strat)
+        decode = scenario.kind == "decode"
+        self.n_pos, self.m, self.decode = n_pos, m, decode
+        sched = (build_schedule(strat.schedule, pp, m, strat.vpp)
+                 if scenario.is_train else forward_only(pp, m))
+        isf_l, pos_l, mic_l, name_l, p2p_l = [], [], [], [], []
+        for d in range(pp):
+            isf = tuple(t.phase == "F" for t in sched[d])
+            pos = tuple(t.chunk * pp + d for t in sched[d])
+            mic = tuple(t.micro for t in sched[d])
+            isf_l.append(isf)
+            pos_l.append(pos)
+            mic_l.append(mic)
+            name_l.append(tuple(f"{'F' if f else 'B'}:s{p}:m{i}"
+                                for f, p, i in zip(isf, pos, mic)))
+            # boundary sends carry the SENDING task's position in both
+            # name and stage (matches the historical activity labels)
+            p2p = []
+            for f, p, i in zip(isf, pos, mic):
+                if f and p < n_pos - 1:
+                    p2p.append(f"P2P:f:s{p}:m{i}")
+                elif f and decode:
+                    # last stage feeds sampled tokens back to stage 0
+                    p2p.append(f"P2P:fb:m{i}")
+                elif not f and p > 0:
+                    p2p.append(f"P2P:b:s{p}:m{i}")
+                else:
+                    p2p.append(None)
+            p2p_l.append(tuple(p2p))
+        self.task_isf: Tuple[Tuple[bool, ...], ...] = tuple(isf_l)
+        self.task_pos: Tuple[Tuple[int, ...], ...] = tuple(pos_l)
+        self.task_micro: Tuple[Tuple[int, ...], ...] = tuple(mic_l)
+        self.task_name: Tuple[Tuple[str, ...], ...] = tuple(name_l)
+        self.task_p2p_name: Tuple[Tuple[Optional[str], ...], ...] = \
+            tuple(p2p_l)
+        self.total_tasks = sum(len(t) for t in isf_l)
+        self._topo: Optional[Tuple[Tuple[int, int], ...]] = None
+
+    @staticmethod
+    def key_for(strat: Strategy, scenario: Scenario, n_pos: int) -> Tuple:
+        schedule = strat.schedule if scenario.is_train else "forward_only"
+        return (schedule, strat.pp, strat.vpp, n_pos,
+                scenario.task_count(strat), scenario.kind == "decode")
+
+    def holds(self, engine: "EventFlowEngine") -> bool:
+        """Whether ``engine``'s lists still hold this structure's own
+        sequences (O(pp) identity check) — an engine whose lists were
+        edited after construction walks its own order."""
+        return all(len(own) == len(mine)
+                   and all(a is b for a, b in zip(own, mine))
+                   for own, mine in ((engine.task_isf, self.task_isf),
+                                     (engine.task_pos, self.task_pos),
+                                     (engine.task_micro, self.task_micro)))
+
+    def topo_order(self) -> Tuple[Tuple[int, int], ...]:
+        if self._topo is None:
+            self._topo = tuple(_topo_walk(
+                self.task_isf, self.task_pos, self.task_micro,
+                self.n_pos, self.m, self.decode))
+        return self._topo
+
+
 class EventFlowEngine:
     """One (stages × strategy × provider) simulation context.
 
@@ -196,13 +337,17 @@ class EventFlowEngine:
     precomputed here and shared across runs. Pass a precomputed
     ``build`` (:class:`EngineBuild`) to share the schedule-independent
     event-mean precomputation across engines that differ only in
-    pipeline schedule / microbatch count.
+    pipeline schedule / microbatch count, and a precomputed
+    ``structure`` (:class:`TaskStructure`) to share the schedule's task
+    lists and topological order across engines that differ only in
+    model, mp, dp or ZeRO-1.
     """
 
     def __init__(self, stages: Sequence[Stage], strat: Strategy,
                  provider: Provider, build: Optional[EngineBuild] = None,
                  scenario: Optional[Scenario] = None,
-                 verify: Optional[bool] = None):
+                 verify: Optional[bool] = None,
+                 structure: Optional[TaskStructure] = None):
         self.strat = strat
         self.provider = provider
         if scenario is None:
@@ -213,7 +358,7 @@ class EventFlowEngine:
         if not scenario.is_train and strat.vpp != 1:
             raise ValueError(
                 f"scenario {scenario.label()!r} supports vpp=1 only")
-        pp, vpp = strat.pp, strat.vpp
+        pp = strat.pp
         m = scenario.task_count(strat)
         self.m = m
         dp = strat.dp
@@ -254,39 +399,25 @@ class EventFlowEngine:
         self.arrival: List[float] = arrivals + [0.0] * (m - len(arrivals))
 
         # ---- schedule task lists as flat per-device metadata ----
-        sched = (build_schedule(strat.schedule, pp, m, vpp)
-                 if scenario.is_train else forward_only(pp, m))
-        self.task_isf: List[List[bool]] = []
-        self.task_pos: List[List[int]] = []
-        self.task_micro: List[List[int]] = []
-        self.task_name: List[List[str]] = []
-        self.task_p2p_name: List[List[Optional[str]]] = []
-        for d in range(pp):
-            isf = [t.phase == "F" for t in sched[d]]
-            pos = [t.chunk * pp + d for t in sched[d]]
-            mic = [t.micro for t in sched[d]]
-            self.task_isf.append(isf)
-            self.task_pos.append(pos)
-            self.task_micro.append(mic)
-            self.task_name.append(
-                [f"{'F' if f else 'B'}:s{p}:m{i}"
-                 for f, p, i in zip(isf, pos, mic)])
-            # boundary sends carry the SENDING task's position in both
-            # name and stage (matches the historical activity labels)
-            p2p = []
-            for f, p, i in zip(isf, pos, mic):
-                if f and p < self.n_pos - 1:
-                    p2p.append(f"P2P:f:s{p}:m{i}")
-                elif f and self._decode:
-                    # last stage feeds sampled tokens back to stage 0
-                    p2p.append(f"P2P:fb:m{i}")
-                elif not f and p > 0:
-                    p2p.append(f"P2P:b:s{p}:m{i}")
-                else:
-                    p2p.append(None)
-            self.task_p2p_name.append(p2p)
-        self.total_tasks = sum(len(t) for t in self.task_isf)
-        self._topo: Optional[List[Tuple[int, int]]] = None
+        # the inner per-device sequences are the structure's (shared
+        # tuples); the outer per-device lists are this engine's own
+        if structure is None:
+            structure = TaskStructure(strat, scenario, self.n_pos)
+        else:
+            want = TaskStructure.key_for(strat, scenario, self.n_pos)
+            if structure.key != want:
+                raise ValueError(f"task structure was built for "
+                                 f"{structure.key!r}, engine wants "
+                                 f"{want!r}")
+        self.structure = structure
+        self.task_isf: List[Sequence[bool]] = list(structure.task_isf)
+        self.task_pos: List[Sequence[int]] = list(structure.task_pos)
+        self.task_micro: List[Sequence[int]] = list(structure.task_micro)
+        self.task_name: List[Sequence[str]] = list(structure.task_name)
+        self.task_p2p_name: List[Sequence[Optional[str]]] = list(
+            structure.task_p2p_name)
+        self.total_tasks = structure.total_tasks
+        self._topo: Optional[Sequence[Tuple[int, int]]] = None
         # bounded FIFO: sweeps alternate two keys (predict + replay);
         # the cap keeps long-lived cached engines from pinning one
         # TimelineBatch per seed set ever requested
@@ -659,7 +790,7 @@ class EventFlowEngine:
     # batched multi-seed replay (one dependency pass, all seeds at once)
     # ------------------------------------------------------------------
 
-    def _topo_order(self) -> List[Tuple[int, int]]:
+    def _topo_order(self) -> Sequence[Tuple[int, int]]:
         """One duration-free dependency-resolution pass.
 
         The task dependency DAG (device serialization + boundary
@@ -669,62 +800,19 @@ class EventFlowEngine:
         are replayed with known/unknown flags instead of times, and the
         pop order is recorded. ``run_batched`` then evaluates the
         timing recurrences along this order with all lanes stacked.
+
+        While this engine's lists still hold its structure's sequences
+        the order is the structure's, walked once for every engine that
+        shares it; lists edited after construction get their own walk.
         """
         if self._topo is not None:
             return self._topo
-        pp, n_pos, m = self.strat.pp, self.n_pos, self.m
-        decode = self._decode
-        f_known = [[False] * m for _ in range(n_pos)]
-        af_known = [[False] * m for _ in range(n_pos)]
-        ab_known = [[False] * m for _ in range(n_pos)]
-        fb_known = [False] * m
-        ptr = [0] * pp
-        n_tasks = [len(t) for t in self.task_isf]
-        order: List[Tuple[int, int]] = []
-        queue: deque = deque()
-        enabled = [False] * pp
-
-        def try_enable(d: int) -> None:
-            if enabled[d] or ptr[d] >= n_tasks[d]:
-                return
-            i = ptr[d]
-            pos, mic = self.task_pos[d][i], self.task_micro[d][i]
-            if self.task_isf[d][i]:
-                if pos == 0:
-                    ok = not decode or mic == 0 or fb_known[mic - 1]
-                else:
-                    ok = af_known[pos][mic]
-            else:
-                ok = f_known[pos][mic] and (pos == n_pos - 1
-                                            or ab_known[pos][mic])
-            if ok:
-                enabled[d] = True
-                queue.append(d)
-
-        for d in range(pp):
-            try_enable(d)
-        while queue:
-            d = queue.popleft()
-            enabled[d] = False
-            i = ptr[d]
-            pos, mic = self.task_pos[d][i], self.task_micro[d][i]
-            if self.task_isf[d][i]:
-                f_known[pos][mic] = True
-                if pos < n_pos - 1:
-                    af_known[pos + 1][mic] = True
-                    try_enable((pos + 1) % pp)
-                elif decode:
-                    fb_known[mic] = True
-                    if d != 0:
-                        try_enable(0)
-            else:
-                if pos > 0:
-                    ab_known[pos - 1][mic] = True
-                    try_enable((pos - 1) % pp)
-            order.append((d, i))
-            ptr[d] += 1
-            try_enable(d)
-
+        if self.structure.holds(self):
+            order = self.structure.topo_order()
+        else:
+            order = _topo_walk(self.task_isf, self.task_pos,
+                               self.task_micro, self.n_pos, self.m,
+                               self._decode)
         if len(order) != self.total_tasks:
             raise RuntimeError(
                 f"pipeline schedule deadlock: {self.strat.label()} "
@@ -733,7 +821,7 @@ class EventFlowEngine:
         self._topo = order
         return order
 
-    def topo_order(self) -> List[Tuple[int, int]]:
+    def topo_order(self) -> Sequence[Tuple[int, int]]:
         """Public accessor for the cached duration-free topological
         order — the contract :class:`repro.core.megabatch.MegaBatch`
         compiles against (step j of the array program evaluates the

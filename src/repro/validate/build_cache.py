@@ -19,6 +19,14 @@ cluster)``, and large parts of the key collapse further:
   (model, strategy) pair recurs across 4 schedules — shares one build
   across the same-vpp schedules of each pair (gpipe/1f1b/pipedream;
   interleaved's vpp=2 builds its own position structure);
+* the **task structure** (:class:`repro.core.engine.TaskStructure` —
+  the schedule's per-device task lists and their duration-free
+  topological order) depends only on the schedule built, pp, vpp, the
+  position count, the task count and whether the scenario is decode —
+  not on mp, dp, ZeRO-1, the model or any event mean — so a search grid
+  (both ZeRO-1 options, many (mp, dp) pairs of one pp and m) builds
+  each structure once and its engines share the inner tuples. It lives
+  in the cache, not in the process: a fresh cache builds its own;
 * the **engine** itself (schedule task lists over a build) is cached on
   the full key, so re-sweeping with a warm cache skips everything.
 
@@ -33,7 +41,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from repro.configs.base import ArchConfig, get_config, smoke_config
-from repro.core.engine import EngineBuild, EventFlowEngine
+from repro.core.engine import EngineBuild, EventFlowEngine, TaskStructure
 from repro.core.events import Stage, Strategy
 from repro.core.hierarchy import build_positions
 from repro.core.profiler import Provider
@@ -51,16 +59,19 @@ class BuildCacheStats:
     build_misses: int = 0
     engine_hits: int = 0
     engine_misses: int = 0
+    structure_hits: int = 0
+    structure_misses: int = 0
     invalidations: int = 0
 
     @property
     def hits(self) -> int:
-        return self.positions_hits + self.build_hits + self.engine_hits
+        return (self.positions_hits + self.build_hits + self.engine_hits
+                + self.structure_hits)
 
     @property
     def misses(self) -> int:
         return (self.positions_misses + self.build_misses
-                + self.engine_misses)
+                + self.engine_misses + self.structure_misses)
 
     def to_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -79,7 +90,8 @@ def _strip_schedule(strat: Strategy) -> Strategy:
 
 
 class BuildCache:
-    """Per-provider cache of positions / engine builds / engines.
+    """Per-provider cache of positions / engine builds / task
+    structures / engines.
 
     All keys are content-addressed (arch name + smoke flag + frozen
     ``Strategy`` + derived microbatch + seq); the cluster is implied by
@@ -91,6 +103,7 @@ class BuildCache:
         self.provider = provider
         self._positions: Dict[Tuple, List[Stage]] = {}
         self._builds: Dict[Tuple, EngineBuild] = {}
+        self._structures: Dict[Tuple, TaskStructure] = {}
         self._engines: Dict[Tuple, EventFlowEngine] = {}
         self._version = provider.cache_version
         self.stats = BuildCacheStats()
@@ -98,8 +111,9 @@ class BuildCache:
     # ------------------------------------------------------------------
 
     def _check_version(self) -> None:
-        """Everything cached here bakes in provider event means — a
-        provider cache clear invalidates all three levels at once."""
+        """Positions, builds and engines bake in provider event means —
+        a provider cache clear invalidates those three levels at once.
+        Task structures hold no provider number and stay."""
         if self._version != self.provider.cache_version:
             self._positions.clear()
             self._builds.clear()
@@ -177,6 +191,19 @@ class BuildCache:
     def _build_created(self, key: Tuple, build: EngineBuild) -> None:
         pass
 
+    def structure_for(self, strat: Strategy, scenario: Scenario,
+                      n_pos: int) -> TaskStructure:
+        key = TaskStructure.key_for(strat, scenario, n_pos)
+        hit = self._structures.get(key)
+        if hit is not None:
+            self.stats.structure_hits += 1
+            return hit
+        self.stats.structure_misses += 1
+        with span("distsim.build.structure"):
+            structure = TaskStructure(strat, scenario, n_pos)
+        self._structures[key] = structure
+        return structure
+
     def engine_for_cfg(self, cfg: ArchConfig, strat: Strategy,
                        global_batch: int, seq: int,
                        scenario: Scenario = TRAIN) -> EventFlowEngine:
@@ -192,8 +219,10 @@ class BuildCache:
         self.stats.engine_misses += 1
         with span("distsim.build.engine"):
             build = self.build_for(cfg, strat, micro, seq, scenario)
+            structure = self.structure_for(strat, scenario, build.n_pos)
             eng = EventFlowEngine(build.stages, strat, self.provider,
-                                  build=build, scenario=scenario)
+                                  build=build, scenario=scenario,
+                                  structure=structure)
         self._engines[key] = eng
         return eng
 
@@ -230,5 +259,6 @@ class BuildCache:
         out = self.stats.to_dict()
         out.update(positions_entries=len(self._positions),
                    build_entries=len(self._builds),
+                   structure_entries=len(self._structures),
                    engine_entries=len(self._engines))
         return out

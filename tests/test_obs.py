@@ -33,6 +33,7 @@ PARENT = {
     "distsim.build.engine": "distsim.search.engines",
     "distsim.build.engine_build": "distsim.build.engine",
     "distsim.build.positions": "distsim.build.engine_build",
+    "distsim.build.structure": "distsim.build.engine",
     "distsim.megabatch.compile": "distsim.search",
     "distsim.megabatch.predict": "distsim.search",
     "distsim.scan.stack": "distsim.megabatch.predict",

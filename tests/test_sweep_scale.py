@@ -13,19 +13,25 @@ Acceptance pins (ISSUE 5):
   means;
 * the >8-way ring extrapolation shares its constants with
   ``costmodel.collective_time`` and stays continuous at the 8->9
-  boundary.
+  boundary;
+* a schedule's task structure is built once per cache and shared by
+  every engine of its key, with bit-identical compiled arrays.
 """
 import copy
 
+import numpy as np
 import pytest
 
 from repro.configs.base import get_config
 from repro.core import (A40_CLUSTER, AnalyticalProvider, DistSim,
                         EngineBuild, Event, EventFlowEngine, Strategy,
                         collective_time, ring_hops, ring_volume_factor)
+from repro.core.engine import TaskStructure
 from repro.core.events import ComposedEvent
+from repro.core.megabatch import MegaBatch
 from repro.core.modelgraph import GEMM
 from repro.core.hierarchy import build_positions
+from repro.core.scenario import TRAIN, Decode, Prefill
 from repro.validate import (BuildCache, ValidationCell, full_matrix,
                             run_sweep, smoke_matrix)
 from repro.validate.report import dump, dumps, load
@@ -150,6 +156,186 @@ def test_full_matrix_extended_with_predict_scale_cells():
     for c in cells:
         assert c.global_batch % (c.strategy.dp
                                  * c.strategy.microbatches) == 0
+
+
+# --------------------------------------------------------------------------
+# build cache: shared task structure
+# --------------------------------------------------------------------------
+
+CFG = get_config("gpt2_345m")
+_LISTS = ("task_isf", "task_pos", "task_micro", "task_name",
+          "task_p2p_name")
+
+
+def _shares(a, b) -> bool:
+    """Every per-device inner sequence of ``a`` is ``b``'s object."""
+    return all(x is y for name in _LISTS
+               for x, y in zip(getattr(a, name), getattr(b, name)))
+
+
+def _pair(cache, **kw):
+    """Engines of one (pp=2, m=4) structure: ZeRO-1 off and on, and an
+    (mp, dp) pair of the same pp and m."""
+    base = dict(mp=1, pp=2, dp=2, microbatches=4)
+    return [cache.engine_for_cfg(CFG, Strategy(**{**base, **over}), 16,
+                                 128, **kw)
+            for over in ({}, {"zero1": True}, {"mp": 2, "dp": 1})]
+
+
+def _direct(strat, seq=128, gb=16):
+    provider = AnalyticalProvider(A40_CLUSTER)
+    micro = strat.microbatch_size(gb)
+    return build_positions(CFG, strat, micro, seq, A40_CLUSTER), strat, \
+        provider
+
+
+def test_structure_shared_across_zero1_and_mp_dp():
+    cache = BuildCache(AnalyticalProvider(A40_CLUSTER))
+    a, z, md = _pair(cache)
+    for other in (z, md):
+        assert _shares(a, other)
+        assert other.structure is a.structure
+        assert other.topo_order() is a.topo_order()
+        # the outer per-device lists stay each engine's own
+        assert all(getattr(a, n) is not getattr(other, n) for n in _LISTS)
+    # a different m, schedule or vpp, or a serving scenario, does not
+    for strat, scen in (
+            (Strategy(mp=1, pp=2, dp=2, microbatches=8), None),
+            (Strategy(mp=1, pp=2, dp=2, microbatches=4,
+                      schedule="gpipe"), None),
+            (Strategy(mp=1, pp=2, dp=2, microbatches=4,
+                      schedule="interleaved", vpp=2), None),
+            (Strategy(mp=1, pp=2, dp=2, microbatches=4), Prefill()),
+            (Strategy(mp=1, pp=2, dp=2, microbatches=4), Decode(steps=4))):
+        kw = {} if scen is None else {"scenario": scen}
+        e = cache.engine_for_cfg(CFG, strat, 16, 128, **kw)
+        assert e.structure is not a.structure
+        assert not any(x is y for x, y in zip(e.task_isf, a.task_isf))
+    # prefill and decode of one (pp, m) differ only in the decode flag
+    pre = cache.engine_for_cfg(CFG, Strategy(mp=1, pp=2, dp=1,
+                                             microbatches=4), 8, 128,
+                               scenario=Prefill())
+    dec = cache.engine_for_cfg(CFG, Strategy(mp=1, pp=2, dp=1,
+                                             microbatches=4), 8, 128,
+                               scenario=Decode(steps=4))
+    assert pre.structure.key[:5] == dec.structure.key[:5]
+    assert pre.structure is not dec.structure
+
+
+def test_structure_counters_and_fresh_cache():
+    cache = BuildCache(AnalyticalProvider(A40_CLUSTER))
+    snap = cache.snapshot()
+    assert snap["structure_entries"] == 0
+    assert snap["structure_hits"] == snap["structure_misses"] == 0
+    _pair(cache)
+    assert (cache.stats.structure_misses, cache.stats.structure_hits) \
+        == (1, 2)
+    _pair(cache)                          # engine hits: no structure lookup
+    assert (cache.stats.structure_misses, cache.stats.structure_hits) \
+        == (1, 2)
+    cache.engine_for_cfg(CFG, Strategy(mp=1, pp=2, dp=2, microbatches=8),
+                         16, 128)
+    assert (cache.stats.structure_misses, cache.stats.structure_hits) \
+        == (2, 2)
+    assert cache.snapshot()["structure_entries"] == 2
+    assert cache.stats.hits == (cache.stats.positions_hits
+                                + cache.stats.build_hits
+                                + cache.stats.engine_hits + 2)
+    # a fresh cache starts with no structure of its own
+    fresh = BuildCache(cache.provider)
+    e = _pair(fresh)[0]
+    assert fresh.stats.structure_misses == 1
+    assert e.structure is not _pair(cache)[0].structure
+
+
+def test_structure_counts_on_small_search_grid():
+    """A search session builds each distinct structure once: the 4-chip
+    grid below asks 64 engines of 18 (schedule, pp, vpp, m) keys."""
+    from repro.search.engine import SearchEngine
+    from repro.search.space import enumerate_candidates
+    args = (4, 8, None, ("1f1b", "gpipe"), (False, True))
+    keys = {(s.schedule, s.pp, s.vpp, s.microbatches)
+            for s in (c.strategy for c in enumerate_candidates(*args))}
+    se = SearchEngine(CFG, A40_CLUSTER, megabatch=True,
+                      megabatch_backend="numpy")
+    se.search(4, 8, 128, schedules=args[3], zero1_options=args[4])
+    st = se.cache.build_cache(A40_CLUSTER).stats
+    assert st.engine_misses == st.structure_misses + st.structure_hits
+    assert st.structure_misses == len(keys)
+    assert (st.structure_misses, st.structure_hits) == (18, 46)
+
+
+def test_structure_inner_sequences_are_tuples():
+    for eng in _pair(BuildCache(AnalyticalProvider(A40_CLUSTER))) + [
+            EventFlowEngine(*_direct(Strategy(mp=1, pp=2, dp=2,
+                                              microbatches=4)))]:
+        for name in _LISTS:
+            outer = getattr(eng, name)
+            assert isinstance(outer, list)
+            assert all(isinstance(seq, tuple) for seq in outer)
+        assert isinstance(eng.topo_order(), tuple)
+
+
+_MB_STRATS = [
+    Strategy(mp=1, pp=2, dp=2, microbatches=4),
+    Strategy(mp=1, pp=2, dp=2, microbatches=4, zero1=True),
+    Strategy(mp=2, pp=2, dp=1, microbatches=4),
+    Strategy(mp=1, pp=4, dp=1, microbatches=4, schedule="gpipe"),
+    Strategy(mp=1, pp=4, dp=1, microbatches=4, schedule="gpipe",
+             zero1=True),
+    Strategy(mp=1, pp=2, dp=2, microbatches=4, schedule="interleaved",
+             vpp=2),
+    Strategy(mp=1, pp=2, dp=1, microbatches=8, schedule="pipedream"),
+]
+
+
+def test_megabatch_arrays_identical_with_shared_structure():
+    cache = BuildCache(AnalyticalProvider(A40_CLUSTER))
+    shared = [cache.engine_for_cfg(CFG, s, 16, 128) for s in _MB_STRATS]
+    assert cache.stats.structure_hits >= 2
+    own = [EventFlowEngine(*_direct(s)) for s in _MB_STRATS]
+    a, b = MegaBatch(shared), MegaBatch(own)
+    for name in ("_out", "_dep0", "_dep1", "_dep2", "_del1", "_del2",
+                 "_dur", "_seg", "_send"):
+        assert np.array_equal(getattr(a, name), getattr(b, name)), name
+    assert list(a.predict_times("numpy")) == list(b.predict_times("numpy"))
+    for x, y in zip(shared, own):
+        assert x.run().batch_time == y.run().batch_time
+        assert list(x.run_batched(SEEDS, jitter_sigma=0.025).batch_times) \
+            == list(y.run_batched(SEEDS, jitter_sigma=0.025).batch_times)
+        assert [(t.name, t.start, t.end) for t in x.run().activities] \
+            == [(t.name, t.start, t.end) for t in y.run().activities]
+
+
+def test_planted_fault_stays_in_its_engine():
+    """Replacing one sharing engine's device entries deadlocks that
+    engine alone: its sibling keeps the structure's order and time."""
+    cache = BuildCache(AnalyticalProvider(A40_CLUSTER))
+    eng, sib, _ = _pair(cache)
+    order, bt = sib.topo_order(), sib.run().batch_time
+    assert eng.topo_order() is order
+    for name in _LISTS:
+        lst = getattr(eng, name)
+        lst[1] = lst[1][::-1]
+    eng._topo = None
+    assert not eng.structure.holds(eng)
+    with pytest.raises(RuntimeError, match="deadlock"):
+        eng.run()
+    with pytest.raises(RuntimeError, match="deadlock"):
+        eng.topo_order()
+    assert sib.structure.holds(sib)
+    assert sib.topo_order() is order
+    assert eng.structure.topo_order() is order
+    assert sib.run().batch_time == bt
+
+
+def test_engine_rejects_mismatched_structure():
+    pos, strat, provider = _direct(Strategy(mp=1, pp=2, dp=2,
+                                            microbatches=4))
+    other = TaskStructure(
+        Strategy(mp=1, pp=2, dp=2, microbatches=8), TRAIN, len(pos))
+    with pytest.raises(ValueError, match="task structure"):
+        EventFlowEngine(pos, strat, provider, structure=other)
 
 
 # --------------------------------------------------------------------------
