@@ -4,8 +4,17 @@ The manifest is ``BENCHMARK.json`` at the root of the checkout. A cell
 (an entry of ``workloads``) names a configuration, whose sizes live in
 ``configs/<name>.json``, and a traffic mix, whose parameters live in
 ``traffic/<name>.json``. The traffic file's ``kind`` picks the module
-that runs the cell (``plan`` or ``search``). Each per-layer metric is a reader of its own
-in ``metrics/<name>.py``. Nothing here knows a cell by name.
+that runs the cell (``plan`` or ``search``). A plan traffic file also
+names the cell's references: ``step_reference`` (a module of
+``reference/``: the plain step), ``step_flops``
+(``"<module>:<function>"``: a step's FLOPs) and ``prediction_reference``
+(a module of ``reference/``: the plain composition of DistSim's
+prediction, given the strategy's ``mp`` and ``dp`` and any
+``prediction_args`` as keywords), and ``limits``, one for each number
+that ``correct`` compares, so a new architecture brings these as files
+of its own. Each
+per-layer metric is a reader of its own in ``metrics/<name>.py``.
+Nothing here knows a cell by name.
 """
 from __future__ import annotations
 

@@ -201,15 +201,33 @@ def leaf_norms(tree) -> Dict[str, float]:
         jnp.ravel(x).astype(jnp.float32))) for p, x in flat}
 
 
+def batch_grad(grad_fn, params, batch) -> Tuple[float, Any]:
+    """Loss and gradient of the batch's mean, one row at a time so that
+    a batch of any size fits one chip: each row's mean weighted by its
+    share of the batch's labelled positions (a one-row batch is its own
+    row, weighted by 1)."""
+    valid = (np.asarray(batch["labels"]) >= 0).sum(axis=1)
+    value, grads = 0.0, None
+    for r, count in enumerate(valid):
+        w = float(count / valid.sum())
+        v, g = grad_fn(params, {k: x[r:r + 1] for k, x in batch.items()})
+        value += w * float(v)
+        grads = (jax.tree.map(lambda x: w * x, g) if grads is None else
+                 jax.tree.map(lambda a, x: a + w * x, grads, g))
+        del g
+    return value, grads
+
+
 def run(conf: Dict[str, Any], adamw: Dict[str, Any], key, batches,
         dtype=jnp.float32) -> Dict[str, Any]:
     """The reference's first three steps: each step's loss, the first
     step's clipped gradient per leaf, and each leaf's change after
-    three steps. ``dtype`` below float32 gives the control."""
+    three steps. ``dtype`` below float32 gives the control. The weights
+    are made again at the end (the same from the same key) rather than
+    kept, which leaves room for a batch's gradients."""
     precision = "highest" if dtype == jnp.float32 else "default"
     with jax.default_matmul_precision(precision):
-        w0 = make_weights(conf, key, dtype)
-        params = jax.tree.map(jnp.copy, w0)
+        params = make_weights(conf, key, dtype)
         mu = jax.tree.map(jnp.zeros_like, params)
         nu = jax.tree.map(jnp.zeros_like, params)
         grad_fn = jax.jit(jax.value_and_grad(
@@ -219,13 +237,13 @@ def run(conf: Dict[str, Any], adamw: Dict[str, Any], key, batches,
             static_argnums=4)
         losses, first_grad = [], None
         for s, batch in enumerate(batches[:3]):
-            value, grads = grad_fn(params, batch)
-            losses.append(float(value))
+            value, grads = batch_grad(grad_fn, params, batch)
+            losses.append(value)
             params, mu, nu, clipped = step_fn(params, grads, mu, nu, s)
             if s == 0:
                 first_grad = leaf_norms(clipped)
             del grads, clipped
         change = leaf_norms(jax.tree.map(
             lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            params, w0))
+            params, make_weights(conf, key, dtype)))
     return {"losses": losses, "grad": first_grad, "change": change}

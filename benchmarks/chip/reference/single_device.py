@@ -16,6 +16,7 @@ backward of every layer, then the optimizer.
   and two MLP matrices; a tied head adds none).
 
 The profiled times are the input: a table from GEMM group to seconds.
+The layout's ``mp`` and ``dp`` are taken and have to be 1.
 """
 from __future__ import annotations
 
@@ -46,10 +47,19 @@ def param_bytes(conf: Dict) -> float:
     return 2 * v * d + conf["n_layers"] * layer + head
 
 
+def _one_device(mp: int, dp: int) -> None:
+    if (mp, dp) != (1, 1):
+        raise ValueError(f"a one-device composition, asked for mp {mp} "
+                         f"x dp {dp}")
+
+
 def compose(conf: Dict, batch: int, seq: int, hbm_bw: float,
-            table: Dict[Group, float], dtype=float) -> float:
-    """Predicted step time from the profiled ``table``; ``dtype``
-    (e.g. ``numpy.float32``) computes it in another precision."""
+            table: Dict[Group, float], dtype=float, *, mp: int = 1,
+            dp: int = 1) -> Dict[str, float]:
+    """The predicted step from the profiled ``table`` and its parts:
+    ``step``, ``compute`` (GEMM groups) and ``opt``; ``dtype`` (e.g.
+    ``numpy.float32``) computes them in another precision."""
+    _one_device(mp, dp)
     g = layout_groups(conf, batch, seq)
     n = conf["n_layers"]
     fwd = dtype(0.0)
@@ -61,13 +71,16 @@ def compose(conf: Dict, batch: int, seq: int, hbm_bw: float,
         bwd = dtype(bwd + dtype(table[g["block_bwd"]]))
     bwd = dtype(bwd + dtype(table[g["head_bwd"]]))
     opt = dtype(6.0 * param_bytes(conf) * 2 / hbm_bw)
-    return float(dtype(dtype(fwd + bwd) + opt))
+    compute = dtype(fwd + bwd)
+    return {"step": float(dtype(compute + opt)), "compute": float(compute),
+            "opt": float(opt)}
 
 
 def missing_groups(conf: Dict, batch: int, seq: int,
-                   profiled: List[Group]) -> int:
+                   profiled: List[Group], mp: int = 1, dp: int = 1) -> int:
     """How many groups the layout needs and the profile lacks, plus how
     many it profiled that the layout does not have."""
+    _one_device(mp, dp)
     want = set(layout_groups(conf, batch, seq).values())
     got = {g for g in profiled if g}
     return len(want ^ got)

@@ -8,6 +8,7 @@ import json
 import jax
 import pytest
 
+import bench_tiny
 import run as bench_run
 
 
@@ -55,26 +56,11 @@ def test_control_is_not_correct(tiny_bench, capsys, workload, fails):
 
 
 def _stale_step(monkeypatch):
-    """A train step that returns its state unchanged."""
-    from repro.train import step as steplib
-    make = steplib.make_train_step
-
-    def stale(*a, **kw):
-        inner = make(*a, **kw)
-
-        def step(params, state, batch):
-            _, _, metrics = inner(params, state, batch)
-            return params, state, metrics
-        return step
-    monkeypatch.setattr(steplib, "make_train_step", stale)
+    bench_tiny.stale_step(monkeypatch.setattr)
 
 
 def _altered_answer(monkeypatch):
-    """The predicted step altered where it is produced."""
-    from repro.core import simulator
-    prop = simulator.SimBatch.batch_time
-    monkeypatch.setattr(simulator.SimBatch, "batch_time", property(
-        lambda self: prop.fget(self) * (1 + 1e-6)))
+    bench_tiny.altered_answer(monkeypatch.setattr)
 
 
 def _altered_scan(monkeypatch):

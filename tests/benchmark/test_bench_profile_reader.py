@@ -41,3 +41,11 @@ def test_left_out_of_an_untraced_run():
     r = harness.Readings(cell="gpt2_345m.plan-1chip")
     r.values.update(answers=2, run_s=4.0)
     assert READ(r) is None
+
+
+def test_programs_on_one_plane_of_several_are_summed():
+    r = _readings([["jit_profile_group(3)", 1.0, 1.0]])
+    r.trace["devices"]["/device:TPU:1"] = {"ops": [["dot", 0.0, 1.0]],
+                                           "modules": []}
+    # the profiler's 1 s lies on chip 0 alone: 1 s of 4 s of run phase
+    assert READ(r) == pytest.approx(75.0)
